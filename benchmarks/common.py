@@ -162,8 +162,8 @@ def assert_two_compile_packs(scenarios: str, seeds: int, *, n_devices=4,
     """The compile-count acceptance guard, shared by the sweep and actor
     benchmarks: a full 4-method x seeds x scenarios grid must pack into
     exactly 2 compiled programs (one per actor family — exit masks and
-    scenario knobs are agent-state data). Executes both packs twice and,
-    where jax exposes ``_cache_size``, pins one compile per program.
+    scenario knobs are agent-state data). Executes both packs twice and
+    pins one compile per program.
     Returns (packs, cells)."""
     from repro.sweep import SweepSpec, pack_cells
     from repro.sweep.runner import PackProgram
@@ -179,9 +179,8 @@ def assert_two_compile_packs(scenarios: str, seeds: int, *, n_devices=4,
     assert {p.family for p in packs} == {"gcn", "mlp"}
     k = len(spec.scenarios)
     assert sum(len(p.cells) for p in packs) == len(cells) == 4 * seeds * k
-    # CompileTracker owns both measurement levels: per-program cache
-    # pins (exact — skipped if a jax upgrade hides the probe) plus the
-    # process-wide compile-event stream for logging
+    # CompileTracker owns both measurement levels: exact per-program
+    # cache pins plus the process-wide compile-event stream for logging
     from repro.obs import CompileTracker
     with CompileTracker() as ct:
         for pack in packs:

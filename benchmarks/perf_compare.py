@@ -11,11 +11,12 @@ import os
 from benchmarks.common import RESULTS_DIR
 from repro.configs import get_arch
 from repro.launch.analysis import flops_bytes_model
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 from repro.launch.specs import arch_for_shape
 from repro.models.config import INPUT_SHAPES
+from repro.obs.peaks import TPU_V5E, chip_peaks
 
 CHIPS = 256
+V5E = chip_peaks(TPU_V5E)  # the pod modelled here is v5e
 
 
 def terms(rec):
@@ -24,9 +25,9 @@ def terms(rec):
     m = flops_bytes_model(cfg, shape)
     wire = sum(c["wire_bytes"] for c in rec.get("collectives", {}).values())
     return {
-        "compute_s": m["flops"] / (CHIPS * PEAK_FLOPS_BF16),
-        "memory_s": m["bytes"] / (CHIPS * HBM_BW),
-        "collective_s": wire / ICI_BW,
+        "compute_s": m["flops"] / (CHIPS * V5E.flops_bf16),
+        "memory_s": m["bytes"] / (CHIPS * V5E.hbm_bw),
+        "collective_s": wire / V5E.ici_bw,
         "temp_gb": rec.get("temp_size_in_bytes", 0) / 1e9,
         "wire_gb": wire / 1e9,
         "opts": ",".join(rec.get("opts", [])) or "baseline",

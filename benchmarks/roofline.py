@@ -24,11 +24,12 @@ import os
 from benchmarks.common import RESULTS_DIR, save_rows
 from repro.configs import get_arch
 from repro.launch.analysis import flops_bytes_model
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 from repro.launch.specs import arch_for_shape
 from repro.models.config import INPUT_SHAPES
+from repro.obs.peaks import TPU_V5E, chip_peaks
 
 CHIPS = 256
+V5E = chip_peaks(TPU_V5E)  # the pod modelled here is v5e
 
 _ADVICE = {
     "compute": ("compute-bound: raise MXU utilization — larger per-device "
@@ -62,10 +63,10 @@ def run(quick: bool = False, path: str | None = None):
         shape = INPUT_SHAPES[shape_name]
         cfg = arch_for_shape(get_arch(arch), shape)
         m = flops_bytes_model(cfg, shape)
-        t_comp = m["flops"] / (CHIPS * PEAK_FLOPS_BF16)
-        t_mem = m["bytes"] / (CHIPS * HBM_BW)
+        t_comp = m["flops"] / (CHIPS * V5E.flops_bf16)
+        t_mem = m["bytes"] / (CHIPS * V5E.hbm_bw)
         wire = sum(c["wire_bytes"] for c in r.get("collectives", {}).values())
-        t_coll = wire / ICI_BW
+        t_coll = wire / V5E.ici_bw
         terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
         dominant = max(terms, key=terms.get)
         rows.append({

@@ -168,4 +168,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.obs.compile import use_compile_cache
+    use_compile_cache()
     main()

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +55,14 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k, v, lengths, *, block_k: int = 256,
-                     interpret: bool = True):
-    """q [B,H,d] (one token), k/v [B,S,KVH,d], lengths [B] -> [B,H,d]."""
+                     interpret: Optional[bool] = None):
+    """q [B,H,d] (one token), k/v [B,S,KVH,d], lengths [B] -> [B,H,d].
+
+    ``interpret=None`` derives the default from the backend (compiled on
+    TPU, interpreter elsewhere), as ``gcn_agg`` does.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
     s, kvh = k.shape[1], k.shape[2]
     g = h // kvh

@@ -33,12 +33,12 @@ def _kernel(hs_ref, hd_ref, ef_ref, ws_ref, bs_ref, wd_ref, wf_ref,
     ef = ef_ref[0].astype(jnp.float32)               # [M, O]
     src = jax.lax.dot_general(hs, ws_ref[...], (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    src = src + bs_ref[...][None, :]                 # [M, E]
+    src = src + bs_ref[...]                          # [M, E]
     dst = jax.lax.dot_general(hd, wd_ref[...], (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # [O, E]
     x = src[:, None, :] + dst[None, :, :] + ef[..., None] * wf_ref[...]
     out = jnp.sum(jnp.maximum(x, 0.0) * wo_ref[...], axis=-1)
-    o_ref[0] = (out + bo_ref[0]).astype(o_ref.dtype)
+    o_ref[0] = (out + bo_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -48,7 +48,9 @@ def edge_score(h_src, h_dst, edge_feat, w_src, b_src, w_dst, w_feat,
     [H,E], b_src/w_feat/w_out [E], b_out [1] -> logits [B,M,O].
 
     ``interpret=None`` derives the default from the backend (compiled on
-    TPU, interpreter elsewhere), mirroring ``gcn_agg``.
+    TPU, interpreter elsewhere), mirroring ``gcn_agg``. The vectors enter
+    as [1, E] rows (and ``b_out`` as [1, 1]) for the same Mosaic block
+    rule ``gcn_agg`` documents.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -63,13 +65,14 @@ def edge_score(h_src, h_dst, edge_feat, w_src, b_src, w_dst, w_feat,
             pl.BlockSpec((1, o, h), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, m, o), lambda i: (i, 0, 0)),
             pl.BlockSpec((h, e), lambda i: (0, 0)),
-            pl.BlockSpec((e,), lambda i: (0,)),
+            pl.BlockSpec((1, e), lambda i: (0, 0)),
             pl.BlockSpec((h, e), lambda i: (0, 0)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((1, e), lambda i: (0, 0)),
+            pl.BlockSpec((1, e), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, m, o), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, o), h_src.dtype),
         interpret=interpret,
-    )(h_src, h_dst, edge_feat, w_src, b_src, w_dst, w_feat, w_out, b_out)
+    )(h_src, h_dst, edge_feat, w_src, b_src.reshape(1, e), w_dst,
+      w_feat.reshape(1, e), w_out.reshape(1, e), b_out.reshape(1, 1))

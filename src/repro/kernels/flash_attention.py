@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,12 +70,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q [B,S,H,d], k/v [B,S,KVH,d] -> [B,S,H,d].
 
-    ``interpret=True`` (default here) runs the kernel body on CPU for
-    validation; on real TPU pass interpret=False.
+    ``interpret=None`` derives the default from the backend (compiled on
+    TPU, interpreter elsewhere), as ``gcn_agg`` does.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, s, h, d = q.shape
     kvh = k.shape[2]
     g = h // kvh

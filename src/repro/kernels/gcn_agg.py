@@ -2,7 +2,7 @@
 
 The paper's hot loop: degree-normalized neighbor aggregation + the
 concat-linear + ReLU, batched over replay minibatches. On TPU the right
-shape is a *dense masked matmul* chain feeding the MXU (DESIGN.md §3):
+shape is a *dense masked matmul* chain feeding the MXU:
 
     agg = (A @ Hn) / (deg + eps);  out = relu(Hs @ Ws + agg @ Wn + b)
 
@@ -33,7 +33,7 @@ def _kernel(adj_ref, hs_ref, hn_ref, ws_ref, wn_ref, b_ref, o_ref):
     pre = pre + jax.lax.dot_general(agg, wn_ref[...],
                                     (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-    o_ref[0] = jax.nn.relu(pre + b_ref[...][None, :]).astype(o_ref.dtype)
+    o_ref[0] = jax.nn.relu(pre + b_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -45,6 +45,10 @@ def gcn_agg(adj, self_feat, nbr_feat, w_self, w_nbr, bias, *,
     ``interpret=None`` derives the default from the backend (compiled on
     TPU, interpreter elsewhere) — the same rule ``ops.py`` applies, so a
     direct caller on TPU gets the real kernel, not the interpreter.
+
+    The bias enters as a [1, H] row: every block then spans its array's
+    last two dims, which is what Mosaic requires, also after ``vmap``
+    over per-cell weights adds a leading batch dim to every operand.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -61,9 +65,9 @@ def gcn_agg(adj, self_feat, nbr_feat, w_self, w_nbr, bias, *,
             pl.BlockSpec((1, o, fn), lambda i: (i, 0, 0)),
             pl.BlockSpec((fs, h), lambda i: (0, 0)),
             pl.BlockSpec((fn, h), lambda i: (0, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
+            pl.BlockSpec((1, h), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, m, h), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, h), self_feat.dtype),
         interpret=interpret,
-    )(adj, self_feat, nbr_feat, w_self, w_nbr, bias)
+    )(adj, self_feat, nbr_feat, w_self, w_nbr, bias.reshape(1, h))

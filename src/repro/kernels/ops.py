@@ -1,10 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU these dispatch to the compiled kernels; on CPU (this container)
-they run the kernel bodies in interpret mode for validation, or fall back
-to the jnp references for speed. The model code keeps its jnp paths as the
-dry-run lowering target (Pallas does not lower on the CPU backend) —
-``use_pallas=True`` is the real-hardware switch. See DESIGN.md §3.
+On TPU these dispatch to the compiled kernels; elsewhere they use the jnp
+references, and ``use_pallas=True`` runs the kernel bodies in interpret
+mode for validation (each kernel picks compiled or interpreted from the
+backend). The model code keeps its jnp paths as the dry-run lowering
+target (Pallas does not lower on the CPU backend).
 
 ``gcn_agg`` and ``edge_score`` — the actor-path kernels the training
 loss differentiates through — carry hand-written VJPs here: Pallas
@@ -42,23 +42,21 @@ def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
         return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                      block_k=block_k, interpret=not _on_tpu())
+                      block_k=block_k)
     return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
 
 
 def decode_attention(q, k, v, lengths, *, block_k=256, use_pallas=None):
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
-        return _decode(q, k, v, lengths, block_k=block_k,
-                       interpret=not _on_tpu())
+        return _decode(q, k, v, lengths, block_k=block_k)
     return _ref.decode_attention_ref(q, k, v, lengths)
 
 
 def ssm_scan(q, k, v, log_w, bonus_u=None, *, chunk=128, use_pallas=None):
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
-        return _ssm(q, k, v, log_w, bonus_u, chunk=chunk,
-                    interpret=not _on_tpu())
+        return _ssm(q, k, v, log_w, bonus_u, chunk=chunk)
     y, _ = _ref.ssm_scan_ref(q, k, v, log_w, bonus_u=bonus_u)
     return y
 
@@ -72,8 +70,7 @@ def _flat2(x):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _gcn_agg(adj, self_feat, nbr_feat, w_self, w_nbr, bias, use):
     if use:
-        return _gcn(adj, self_feat, nbr_feat, w_self, w_nbr, bias,
-                    interpret=not _on_tpu())
+        return _gcn(adj, self_feat, nbr_feat, w_self, w_nbr, bias)
     return _ref.gcn_agg_ref(adj, self_feat, nbr_feat, w_self, w_nbr, bias)
 
 
@@ -126,7 +123,7 @@ def _edge_score(h_src, h_dst, ef, w_src, b_src, w_dst, w_feat, w_out,
                 b_out, use):
     if use:
         return _edge(h_src, h_dst, ef, w_src, b_src, w_dst, w_feat,
-                     w_out, b_out, interpret=not _on_tpu())
+                     w_out, b_out)
     return _ref.edge_score_ref(h_src, h_dst, ef, w_src, b_src, w_dst,
                                w_feat, w_out, b_out)
 

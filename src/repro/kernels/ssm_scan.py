@@ -19,6 +19,7 @@ no decay clamping); diagonal sub-blocks are exact in log space.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -91,13 +92,17 @@ def _kernel(q_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssm_scan(q, k, v, log_w, bonus_u=None, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """q,k [B,T,H,dk], v [B,T,H,dv], log_w [B,T,H,dk] -> y [B,T,H,dv].
 
     bonus_u [H, dk] selects RWKV semantics; None selects Mamba/SSD.
     (Final state stays in scratch — use the jnp reference when the carried
     state must be returned, e.g. at prefill→decode handoff.)
+    ``interpret=None`` derives the default from the backend (compiled on
+    TPU, interpreter elsewhere), as ``gcn_agg`` does.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     c = min(chunk, t)

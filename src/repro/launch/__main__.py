@@ -28,6 +28,8 @@ def main() -> None:
     if cmd not in commands:
         print(f"unknown command {cmd!r}; choose from {', '.join(commands)}")
         raise SystemExit(2)
+    from repro.obs.compile import use_compile_cache
+    use_compile_cache()
     if cmd == "sweep":
         from repro.launch.sweep import main as run
         run(argv)

@@ -19,6 +19,9 @@ from __future__ import annotations
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# The dry-run fakes its mesh on host devices: it and every child it
+# starts stay off the accelerator, which another process may hold.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import/device query (device count locks on init).
 
 import argparse
@@ -82,6 +85,8 @@ def run_one(arch: str, shape_name: str, mesh_kind: str) -> dict:
     from repro.train.steps import make_prefill_step, make_serve_step, \
         make_train_step
 
+    # covers a jax imported before this module set JAX_PLATFORMS
+    jax.config.update("jax_platforms", "cpu")
     t0 = time.time()
     cfg = S.arch_for_shape(get_arch(arch), INPUT_SHAPES[shape_name])
     shape = INPUT_SHAPES[shape_name]
@@ -124,8 +129,6 @@ def run_one(arch: str, shape_name: str, mesh_kind: str) -> dict:
         rec["compile_s"] = round(time.time() - t1, 2)
 
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         rec["flops"] = float(ca.get("flops", -1.0))
         rec["bytes_accessed"] = float(ca.get("bytes accessed", -1.0))
         try:

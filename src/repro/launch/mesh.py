@@ -22,9 +22,3 @@ def make_host_mesh():
     """Whatever this host actually has — used by smoke tests/examples."""
     n = len(jax.devices())
     return jax.make_mesh((n, 1), ("data", "model"))
-
-
-# Hardware constants (TPU v5e) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link

@@ -16,9 +16,10 @@ levels behind one context manager:
   callable; ``counts()`` reads each one's compile-cache size. A freshly
   constructed jit wrapper starts at zero entries, so this is the exact
   per-program count the pack guards assert — unaffected by anything
-  else the process compiled. ``_cache_size`` is jax-internal; where a
-  jax upgrade removes it, ``counts()`` reports ``None`` for that entry
-  and ``assert_counts`` skips it rather than failing the guard itself.
+  else the process compiled.
+
+``use_compile_cache()`` places JAX's persistent compilation cache; every
+entry point calls it once before it compiles anything.
 
 Usage::
 
@@ -31,7 +32,8 @@ Usage::
 """
 from __future__ import annotations
 
-from typing import Optional
+import os
+from pathlib import Path
 
 import jax
 
@@ -40,14 +42,24 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 COMPILE_EVENT_PREFIX = "/jax/core/compile/"
 
 
-def _unregister_duration_listener(cb) -> bool:
-    """Best-effort unregister (the public API has no removal hook)."""
-    try:
-        from jax._src import monitoring as _m
-        _m._unregister_event_duration_listener_by_callback(cb)
-        return True
-    except Exception:
-        return False
+# <checkout>/.jax_cache (this file is <checkout>/src/repro/obs/compile.py):
+# fixed, because a later process must find the same directory again.
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no directory is set here. Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``, so one run's compiles are found again by
+    the next run from the same checkout.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
 
 
 class CompileTracker:
@@ -71,7 +83,7 @@ class CompileTracker:
 
     def __exit__(self, *exc) -> None:
         self._active = False
-        _unregister_duration_listener(self._listener)
+        jax.monitoring.unregister_event_duration_listener(self._listener)
 
     # ------------------------------------------------------- event stream
     @property
@@ -90,11 +102,9 @@ class CompileTracker:
         self._tracked[name] = fn
 
     @staticmethod
-    def cache_size(fn) -> Optional[int]:
-        """Compile-cache entries of one jitted callable (None if the
-        jax internal that exposes it is unavailable)."""
-        size = getattr(fn, "_cache_size", None)
-        return None if size is None else int(size())
+    def cache_size(fn) -> int:
+        """Compile-cache entries of one jitted callable."""
+        return int(fn._cache_size())
 
     def counts(self) -> dict:
         return {name: self.cache_size(fn)
@@ -103,16 +113,12 @@ class CompileTracker:
     def assert_counts(self, expected: dict) -> dict:
         """Assert each tracked function compiled exactly N times.
 
-        Entries whose cache size is unreadable (jax upgrade) are
-        skipped — the guard must not fail because its probe vanished.
         Returns the observed counts.
         """
         got = self.counts()
         for name, want in expected.items():
-            n = got.get(name)
-            if n is not None:
-                assert n == want, (f"{name}: {n} compiled programs, "
-                                   f"expected {want}")
+            assert got[name] == want, (f"{name}: {got[name]} compiled "
+                                       f"programs, expected {want}")
         return got
 
     # ------------------------------------------------------------ summary

@@ -23,9 +23,8 @@ changes FLOPs or arithmetic intensity shows up as a step change in the
 trend, noise-free. ``benchmarks/cost_attribution.py`` reports them into
 ``results/history/`` alongside the wall-clock rows.
 
-Cost analysis is backend-dependent and not guaranteed by the jax API;
-every probe degrades to ``None`` fields (never an exception) so callers
-can log "unavailable" rather than crash on an exotic runtime.
+A field the backend's analysis does not report comes back as ``None``;
+a failing analysis raises.
 """
 from __future__ import annotations
 
@@ -36,16 +35,6 @@ import jax.numpy as jnp
 
 # The three standard hot programs, in reporting order.
 HOT_PROGRAMS = ("driver_step", "sweep_pack", "serve_decode")
-
-
-def _analysis_dict(analysis) -> dict:
-    """Normalize ``cost_analysis()`` output (dict, or list of per-device
-    dicts — take device 0) to one flat dict."""
-    if analysis is None:
-        return {}
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    return dict(analysis)
 
 
 def program_cost(fn, *args, **kwargs) -> dict:
@@ -59,7 +48,8 @@ def program_cost(fn, *args, **kwargs) -> dict:
          "argument_bytes": ..., "output_bytes": ..., "temp_bytes": ...,
          "generated_code_bytes": ...}
 
-    with ``None`` for any field the backend does not expose.
+    with ``None`` for a FLOPs or bytes figure the backend's cost analysis
+    does not report.
     """
     if not hasattr(fn, "lower"):
         fn = jax.jit(fn)
@@ -68,10 +58,7 @@ def program_cost(fn, *args, **kwargs) -> dict:
            "arithmetic_intensity": None, "argument_bytes": None,
            "output_bytes": None, "temp_bytes": None,
            "generated_code_bytes": None}
-    try:
-        ca = _analysis_dict(compiled.cost_analysis())
-    except Exception:
-        ca = {}
+    ca = compiled.cost_analysis()
     flops = ca.get("flops")
     nbytes = ca.get("bytes accessed")
     if flops is not None:
@@ -80,18 +67,13 @@ def program_cost(fn, *args, **kwargs) -> dict:
         out["bytes_accessed"] = float(nbytes)
     if flops and nbytes:
         out["arithmetic_intensity"] = round(float(flops) / float(nbytes), 4)
-    try:
-        mem = compiled.memory_analysis()
-        for field, key in (("argument_size_in_bytes", "argument_bytes"),
-                           ("output_size_in_bytes", "output_bytes"),
-                           ("temp_size_in_bytes", "temp_bytes"),
-                           ("generated_code_size_in_bytes",
-                            "generated_code_bytes")):
-            v = getattr(mem, field, None)
-            if v is not None:
-                out[key] = int(v)
-    except Exception:
-        pass
+    mem = compiled.memory_analysis()
+    for field, key in (("argument_size_in_bytes", "argument_bytes"),
+                       ("output_size_in_bytes", "output_bytes"),
+                       ("temp_size_in_bytes", "temp_bytes"),
+                       ("generated_code_size_in_bytes",
+                        "generated_code_bytes")):
+        out[key] = int(getattr(mem, field))
     return out
 
 

@@ -45,10 +45,7 @@ def span(name: str):
     """Profiler annotation for *host-side* code (serving loop, bench
     harnesses). Shows up as a named region in captured traces; ~free
     when no trace is active."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:              # profiler unavailable on this backend
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager
@@ -56,23 +53,16 @@ def trace_capture(outdir: str, *, enabled: bool = True):
     """Capture a jax profiler trace into ``outdir`` while the block runs.
 
     ``enabled=False`` makes this a no-op so call sites can thread an
-    opt-in flag straight through. The directory is created; a capture
-    that fails to start (e.g. another trace already active) degrades to
-    a warning rather than killing the run — profiling must never take
-    down the job it observes.
+    opt-in flag straight through. The directory is created. A capture
+    that fails to start (e.g. another trace already active) raises: a
+    run that asked for a trace must not silently come back without one.
     """
     if not enabled:
         yield None
         return
     os.makedirs(outdir, exist_ok=True)
-    started = False
+    jax.profiler.start_trace(outdir)
     try:
-        jax.profiler.start_trace(outdir)
-        started = True
-    except Exception as e:          # pragma: no cover - env-dependent
-        print(f"[obs] profiler trace unavailable: {e}", flush=True)
-    try:
-        yield outdir if started else None
+        yield outdir
     finally:
-        if started:
-            jax.profiler.stop_trace()
+        jax.profiler.stop_trace()

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from repro.core.policy import AgentDef, AgentState
 from repro.rollout.driver import RolloutDriver
 from repro.rollout.metrics import metrics_finalize
-from repro.sharding.fleet import fleet_mesh, shard_leading_axis
+from repro.sharding.fleet import (fleet_mesh, map_leading_axis,
+                                  shard_leading_axis)
 
 # Default search box for sampled member hyperparameters (lr is drawn
 # log-uniformly; gain/tau uniformly). PBT perturbations clip back into
@@ -200,7 +201,8 @@ class PopulationDriver:
     def _episode(self, carries, sps, hypers):
         """Run every member's episode; returns (final carries, metrics
         dict of [P] float32 arrays from ``metrics_finalize``)."""
-        carries = jax.vmap(self._scan_body(self.drv))(carries, sps, hypers)
+        carries = map_leading_axis(jax.vmap(self._scan_body(self.drv)),
+                                   self.mesh)(carries, sps, hypers)
         mets = jax.vmap(lambda m: metrics_finalize(
             m, slot_s=float(self.adef.env.cfg.slot_s),
             n_fleets=self.n_fleets))(carries.metrics)
@@ -253,8 +255,9 @@ class PopulationDriver:
 
             def ev(pop_, key_, sps_):
                 carries = self._begin(pop_, key_, sps_)
-                body = self._scan_body(self._eval_drv)
-                carries = jax.vmap(body)(carries, sps_, pop_.hypers)
+                body = map_leading_axis(
+                    jax.vmap(self._scan_body(self._eval_drv)), self.mesh)
+                carries = body(carries, sps_, pop_.hypers)
                 return jax.vmap(lambda m: metrics_finalize(
                     m, slot_s=float(self.adef.env.cfg.slot_s),
                     n_fleets=self.n_fleets))(carries.metrics)

@@ -25,7 +25,8 @@ def fleet_mesh(n_devices: Optional[int] = None) -> Optional[Mesh]:
     n = min(n_devices or len(devices), len(devices))
     if n <= 1:
         return None
-    # Mesh directly (not jax.make_mesh) to keep the jax>=0.4.30 floor
+    # Mesh directly: jax.make_mesh defaults to Explicit axes, and the
+    # fleet placement relies on Auto (compiler-propagated) sharding
     return Mesh(np.array(devices[:n]), (FLEET_AXIS,))
 
 
@@ -52,6 +53,25 @@ def shard_leading_axis(tree, mesh: Optional[Mesh]):
         return jax.device_put(x, NamedSharding(mesh, spec))
 
     return jax.tree_util.tree_map(put, tree)
+
+
+def map_leading_axis(fn, mesh: Optional[Mesh]):
+    """Run ``fn`` on each device's block of the leading axis.
+
+    ``fn`` must treat that axis as a batch (a ``vmap`` over cells or
+    members), with every argument and output leaf carrying it. The
+    compiler cannot partition a Pallas (Mosaic) kernel over a sharded
+    operand, so the cell and member programs are mapped explicitly: each
+    device runs ``fn`` on its own block and no collective is needed.
+    ``mesh=None`` returns ``fn`` unchanged.
+    """
+    if mesh is None:
+        return fn
+    spec = P(FLEET_AXIS)
+    # check_vma off: a Pallas call's out_shape carries no varying-axes
+    # annotation, and nothing here crosses devices to check
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def replicate(tree, mesh: Optional[Mesh]):

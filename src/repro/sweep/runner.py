@@ -36,7 +36,8 @@ from repro.obs.telemetry import telemetry_host, telemetry_summary
 from repro.rollout.driver import (RolloutDriver, carry_metrics,
                                   carry_telemetry)
 from repro.rollout.metrics import metrics_finalize
-from repro.sharding.fleet import pad_to_devices, shard_leading_axis
+from repro.sharding.fleet import (map_leading_axis, pad_to_devices,
+                                  shard_leading_axis)
 from repro.sweep.packer import Pack, pack_cells
 from repro.sweep.spec import Cell, SweepSpec, cell_keys
 from repro.sweep.store import SweepStore
@@ -133,12 +134,17 @@ class PackProgram:
             rkeys, states, sps)
         self._carries, self._sps = shard_leading_axis((carries, sps), mesh)
 
-        def episode(cs, ss):
+        def run_cells(cs, ss):
             def step(c, _):
                 new_c, _ = jax.vmap(drv._slot)(c, ss)
                 return new_c, None
 
-            final, _ = jax.lax.scan(step, cs, None, length=ref.n_slots)
+            return jax.lax.scan(step, cs, None, length=ref.n_slots)[0]
+
+        run_cells = map_leading_axis(run_cells, mesh)
+
+        def episode(cs, ss):
+            final = run_cells(cs, ss)
             fin = jax.vmap(lambda m: metrics_finalize(
                 m, slot_s=env.cfg.slot_s,
                 n_fleets=ref.n_fleets))(final.metrics)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import SyntheticImages
-from repro.mec.profiles import TPU_V5E_HBM_BW, TPU_V5E_PEAK_FLOPS
+from repro.obs.peaks import TPU_V5E, chip_peaks
 from repro.optim import adam
 from repro.optim.optimizers import apply_updates
 from repro.vgg.model import N_EXITS, VGG16EE
@@ -115,6 +115,7 @@ def profile_exits(params, *, width_mult: float = 0.25, eval_batches: int = 20,
         n += batch
 
     flops = VGG16EE.exit_flops(width_mult)
+    v5e = chip_peaks(TPU_V5E)
     rows = []
     for e in candidate_exits:
         row = {"exit": e, "accuracy": acc[e] / n, "gflops": flops[e]}
@@ -126,9 +127,9 @@ def profile_exits(params, *, width_mult: float = 0.25, eval_batches: int = 20,
             for _ in range(10):
                 jax.block_until_ready(fwd[e](params, img1))
             row["cpu_ms"] = (time.perf_counter() - t0) * 100.0
-        # analytic TPU-v5e roofline latency (DESIGN.md §3)
-        t_comp = flops[e] * 1e9 / (TPU_V5E_PEAK_FLOPS * 0.15)
-        t_mem = flops[e] * 1e9 * 0.05 / TPU_V5E_HBM_BW  # ~bytes ≈ 5% of FLOPs
+        # analytic TPU-v5e roofline latency from the published peaks
+        t_comp = flops[e] * 1e9 / (v5e.flops_bf16 * 0.15)
+        t_mem = flops[e] * 1e9 * 0.05 / v5e.hbm_bw  # ~bytes ≈ 5% of FLOPs
         row["tpu_v5e_ms"] = (max(t_comp, t_mem) + 50e-6) * 1e3
         rows.append(row)
     return rows

@@ -91,8 +91,6 @@ def test_flops_model_vs_cost_analysis_scanfree():
              "labels": jnp.zeros((b, s), jnp.int32)}
     compiled = jax.jit(step).lower(state, batch).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<0.5 returns one dict per device
-        cost = cost[0]
     hlo_flops = cost["flops"]
     # correct for the layer scan (2 layers counted once)
     shape = ShapeSpec("t", s, b, "train")
