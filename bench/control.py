@@ -1,0 +1,63 @@
+"""The correctness check's two readings for a serving cell, on the chip.
+
+    python3 -m bench.control --workload <cell> --seeds 1,2,3 --seconds 5
+
+For each seed it runs the cell as ``bench.run`` does, with a short
+window at the cell's own load, and prints one JSON line: the program's
+``logit_gap`` (what a run compares with its limit) and the control's,
+the plain reference with fp8 weights read at the same positions of the
+same requests. The program's readings over a dozen seeds or more set
+the lower end of the limit, the control's the upper end.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+
+def control_gap(ref, cfg, checked) -> float:
+    """The control's ``logit_gap`` over the rows a run checked."""
+    from bench.drivers.serve import check_gaps
+    w = checked["weights"]
+    return check_gaps(lambda t, e, s: ref.control_gaps(cfg, w, t, e), cfg,
+                      checked["rows"], checked["length"])
+
+
+def main(argv=None, *, require_tpu: bool = True, resolved=None) -> list:
+    from bench import manifest, run
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--seconds", type=float, default=5.0)
+    args = ap.parse_args(argv)
+    cell = resolved or manifest.resolve(args.workload)
+    sys.path.insert(0, str(run.ROOT / "src"))
+    if require_tpu:
+        jax = run.setup_jax()
+    else:
+        import jax
+    devs = run.devices(jax, cell["cell"]["chips"], require_tpu)
+    out = []
+    for seed in [int(s) for s in args.seeds.split(",")]:
+        with jax.default_device(devs[0]):
+            res = cell["driver"].run(dict(
+                config=cell["config"], traffic=cell["traffic"],
+                reference=cell["reference"], seed=seed,
+                seconds=args.seconds, trace=False, trace_dir=None,
+                t_process=time.time(), peaks=None))
+            low = control_gap(cell["reference"], cell["config"],
+                              res["checked"])
+        row = {"workload": args.workload, "seed": seed,
+               "program": res["checks"]["logit_gap"][0], "control": low,
+               "limit": res["checks"]["logit_gap"][1],
+               "checked_tokens": res["checked_tokens"],
+               "attempted": res["attempted"]}
+        print(json.dumps(row), flush=True)
+        out.append(row)
+    return out
+
+
+if __name__ == "__main__":
+    main()

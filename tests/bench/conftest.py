@@ -1,0 +1,8 @@
+"""Tests of the chip benchmark under ``bench/``, on the CPU at tiny sizes."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
