@@ -1,0 +1,9 @@
+"""idle_sched.serve: share of the traced window in which no op runs on
+the device while the host is inside ``serve/price``, the scheduling
+phase of a call, in percent (exact interval intersection). None where
+the program has no such span. Moves ``serve_tokens_per_s``."""
+from bench import spans
+
+
+def read(ctx):
+    return spans.idle_inside(ctx["events"], "serve/price")

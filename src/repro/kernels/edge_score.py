@@ -74,5 +74,6 @@ def edge_score(h_src, h_dst, edge_feat, w_src, b_src, w_dst, w_feat,
         out_specs=pl.BlockSpec((1, m, o), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, o), h_src.dtype),
         interpret=interpret,
+        name="edge_score",
     )(h_src, h_dst, edge_feat, w_src, b_src.reshape(1, e), w_dst,
       w_feat.reshape(1, e), w_out.reshape(1, e), b_out.reshape(1, 1))

@@ -70,4 +70,5 @@ def gcn_agg(adj, self_feat, nbr_feat, w_self, w_nbr, bias, *,
         out_specs=pl.BlockSpec((1, m, h), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, h), self_feat.dtype),
         interpret=interpret,
+        name="gcn_agg",
     )(adj, self_feat, nbr_feat, w_self, w_nbr, bias.reshape(1, h))

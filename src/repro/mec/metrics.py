@@ -20,17 +20,20 @@ class RunningMetrics:
     slots: int = 0
     slot_s: float = 30e-3
 
-    def update(self, result, active=None) -> None:
-        success = np.asarray(result.success)
-        acc = np.asarray(result.accuracy)
+    def update(self, result, active=None, *, read=np.asarray) -> None:
+        """Fold one slot in. ``read`` converts each device array to the
+        host (four reads, or three without ``active``); the serving
+        engine passes its spanned, counted reader."""
+        success = read(result.success)
+        acc = read(result.accuracy)
         if active is None:
             active = np.ones_like(success, dtype=bool)
         else:
-            active = np.asarray(active) > 0.5
+            active = read(active) > 0.5
         self.total_tasks += int(active.sum())
         self.successful += int((success & active).sum())
         self.accuracy_sum += float((acc * (success & active)).sum())
-        self.reward_sum += float(result.reward)
+        self.reward_sum += float(read(result.reward))
         self.slots += 1
 
     @property

@@ -8,12 +8,16 @@ Four legs (see docs/ARCHITECTURE.md "Observability layer"):
               one host transfer per episode/pack
   compile   — ``CompileTracker``: jax.monitoring compile events + exact
               per-jit-function compile-count pins (the pack guards)
-  profile   — opt-in ``jax.profiler`` trace capture, ``phase``/``span``
-              annotations around actor/critic/env/train
+  profile   — opt-in ``jax.profiler`` trace capture, ``phase`` scopes
+              around actor/critic/env/train, and the serving engine's
+              host spans: ``serve/price`` and ``serve/decode`` per layer
+              of a call, ``serve/pull`` per device->host read (``pull``)
   log       — JSONL run logs (manifest with config signature + git rev,
               per-episode telemetry snapshots, bench rows), NaN-safe
   history   — append-only cross-run record store (``results/history/``),
-              manifest-stamped for apples-to-apples comparison
+              manifest-stamped for apples-to-apples comparison; fed by
+              benchmark rows, sweep cells and population generations
+              (the serve snapshot does not feed it)
   regress   — noise-aware (median/MAD) perf-regression verdicts over
               the history store, the CI sentinel's engine
   cost      — static FLOPs/bytes/arithmetic-intensity attribution for
@@ -34,7 +38,7 @@ from repro.obs.telemetry import (
     telemetry_update,
 )
 from repro.obs.compile import CompileTracker
-from repro.obs.profile import PHASES, phase, span, trace_capture
+from repro.obs.profile import phase, pull, span, trace_capture
 from repro.obs.log import RunLog, json_safe, read_events, run_manifest
 from repro.obs.history import (HistoryStore, default_store,
                                history_manifest)
@@ -50,7 +54,7 @@ __all__ = [
     "telemetry_init", "telemetry_update", "telemetry_host",
     "telemetry_summary", "rollout_telemetry",
     "CompileTracker",
-    "PHASES", "phase", "span", "trace_capture",
+    "phase", "pull", "span", "trace_capture",
     "RunLog", "json_safe", "read_events", "run_manifest",
     "HistoryStore", "default_store", "history_manifest",
     "check_history", "metric_direction", "regression_verdict",

@@ -24,9 +24,8 @@ regression sentinel (``obs/regress.py``) only compares records sharing
 never gates a TPU number. Producers: ``benchmarks/common.save_rows`` /
 ``merge_bench_rows`` append one ``bench`` record per row,
 ``sweep.runner.run_sweep(..., history=...)`` one ``sweep`` record per
-executed cell, ``EdgeServingEngine.telemetry_snapshot(history=...)``
-one ``serve`` record per snapshot, and
-``pop.trainer.PopulationTrainer`` one ``pop`` record per generation.
+executed cell, and ``pop.trainer.PopulationTrainer`` one ``pop`` record
+per generation.
 """
 from __future__ import annotations
 

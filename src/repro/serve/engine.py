@@ -46,6 +46,7 @@ from repro.mec.metrics import RunningMetrics
 from repro.mec.profiles import llm_exit_profile
 from repro.models.config import ArchConfig
 from repro.models.lm import model_for
+from repro.obs.profile import pull, span
 from repro.obs.telemetry import (hist_quantile, rollout_telemetry,
                                  serve_telemetry, serve_telemetry_update,
                                  telemetry_host, telemetry_summary,
@@ -197,7 +198,7 @@ class _ServingCore:
         # long), so throughput reads as tokens/s next to requests/s
         self.tokens_served = 0
         self.transfers = {"decode_h2d": 0, "decode_d2h": 0,
-                          "telemetry_pulls": 0}
+                          "telemetry_pulls": 0, "host_pulls": 0}
         self._tel_update = jax.jit(
             lambda tel, dec, res, act, dl, rf, loss: telemetry_update(
                 tel, decisions=dec, result=res, active=act, deadline_s=dl,
@@ -206,6 +207,10 @@ class _ServingCore:
 
     def _make_telemetry(self):
         return rollout_telemetry(self.env.N, self.env.L)
+
+    def _pull(self, x) -> np.ndarray:
+        """One device->host read, spanned and counted in ``transfers``."""
+        return pull(x, self.transfers)
 
     # ---------------------------------------------------------- shared step
     def _price_slot(self, active: np.ndarray):
@@ -238,13 +243,13 @@ class _ServingCore:
             replay_frac = jnp.zeros((), jnp.float32)
         self.mec_state, result = self.env.step(self.mec_state, tasks,
                                                decision, self._sp)
-        self.metrics.update(result, tasks.active)
+        self.metrics.update(result, tasks.active, read=self._pull)
         deadline = (self._sp.deadline_s if self._sp is not None
                     else self.env.params.deadline_s)
         self.telemetry = self._tel_update(self.telemetry, decision, result,
                                           tasks.active, deadline,
                                           replay_frac, loss)
-        return tasks, np.asarray(decision), result
+        return tasks, self._pull(decision), result
 
     def _assignment(self, decision: np.ndarray, slot: int):
         """Decode one slot's decision into (replica name, exit layer)."""
@@ -300,8 +305,7 @@ class _ServingCore:
     def _extra_summary(self, summary: dict) -> None:
         """Hook: subclasses fold engine-specific summary keys in place."""
 
-    def telemetry_snapshot(self, *, history=None,
-                           name: str = "serve") -> dict:
+    def telemetry_snapshot(self) -> dict:
         """Host view of the request telemetry (one device->host pull).
 
         ``summary`` carries the derived headline numbers
@@ -313,9 +317,9 @@ class _ServingCore:
         the histogram estimates' ground truth. Before any request is
         served every quantile is ``None`` and every rate 0 (never NaN —
         the snapshot is strict-JSON as is). ``transfers`` counts the
-        engine's host<->device round-trips. ``history`` (a
-        ``repro.obs.HistoryStore``) appends the summary as one
-        manifest-stamped ``serve`` record under ``name``.
+        engine's host<->device round-trips; ``host_pulls`` is every
+        blocking device->host read on the serving path, one per
+        ``serve/pull`` span.
         """
         host = telemetry_host(self.telemetry)
         summary = telemetry_summary(host)
@@ -335,18 +339,6 @@ class _ServingCore:
         host["summary"] = summary
         self.transfers["telemetry_pulls"] += 1
         host["transfers"] = dict(self.transfers)
-        if history is not None:
-            from repro.obs.history import history_manifest
-            metrics = {k: v for k, v in summary.items()
-                       if isinstance(v, (int, float))
-                       and not isinstance(v, bool)}
-            history.append(
-                "serve", name, metrics,
-                manifest=history_manifest(
-                    config_signature=self.env.cfg.static_signature(),
-                    use_pallas=(self.agent_def.use_pallas
-                                if self.agent_def is not None else None)),
-                transfers=dict(self.transfers))
         return host
 
     def make_request(self, prompt_len: int = 8, max_new: int = 8) -> Request:
@@ -377,40 +369,43 @@ class EdgeServingEngine(_ServingCore):
 
     # ------------------------------------------------------------- decoding
     def _decode(self, requests: list[Request], exit_layer: int) -> list:
-        """Greedy-decode a batch at the given exit depth.
+        """Greedy-decode a batch at the given exit depth, under one
+        ``serve/decode`` span.
 
-        Observations stay device-side: the padded prompt matrix goes up
-        in **one** host->device transfer, every per-position input is a
+        The padded prompt matrix goes up in one explicit host->device
+        upload (``decode_h2d`` counts it), every per-position input is a
         device-side select between the next prompt column and the token
         just generated (teacher-forcing while inside each prompt), and
-        the generated tokens come back in **one** device->host transfer
-        at the end. ``transfers`` counts both — the old path re-built a
-        host array per decode position, forcing a round-trip each step.
+        the generated tokens come back in **one** device->host read at
+        the end (``decode_d2h``, and a ``serve/pull``). The per-position
+        Python scalars (``pos`` and its comparison with the prompt
+        lengths) still go up implicitly, as a ``DevicePut`` each step.
         """
-        b = len(requests)
-        cache = self.model.init_cache(self.cfg, b, self.cache_len)
-        prompts = [np.asarray(r.tokens, np.int32) for r in requests]
-        lens = np.array([len(p) for p in prompts], np.int32)
-        total = int(lens.max()) + max(r.max_new for r in requests)
-        mat = np.zeros((b, total), np.int32)
-        for i, p in enumerate(prompts):
-            mat[i, : len(p)] = p
-        prompt_mat = jnp.asarray(mat)              # the one h2d transfer
-        lens_d = jnp.asarray(lens)
-        self.transfers["decode_h2d"] += 1
-        step = self._steps[exit_layer]
-        cur = prompt_mat[:, 0]
-        toks = []
-        for pos in range(total):
-            logits, cache = step(self.params, cache, cur,
-                                 jnp.full((b,), pos, jnp.int32))
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            toks.append(nxt)
-            if pos + 1 < total:
-                cur = jnp.where(pos + 1 < lens_d,
-                                prompt_mat[:, pos + 1], nxt)
-        gen = np.asarray(jnp.stack(toks, axis=1))  # the one d2h transfer
-        self.transfers["decode_d2h"] += 1
+        with span("serve/decode"):
+            b = len(requests)
+            cache = self.model.init_cache(self.cfg, b, self.cache_len)
+            prompts = [np.asarray(r.tokens, np.int32) for r in requests]
+            lens = np.array([len(p) for p in prompts], np.int32)
+            total = int(lens.max()) + max(r.max_new for r in requests)
+            mat = np.zeros((b, total), np.int32)
+            for i, p in enumerate(prompts):
+                mat[i, : len(p)] = p
+            prompt_mat = jnp.asarray(mat)      # the explicit upload
+            lens_d = jnp.asarray(lens)
+            self.transfers["decode_h2d"] += 1
+            step = self._steps[exit_layer]
+            cur = prompt_mat[:, 0]
+            toks = []
+            for pos in range(total):
+                logits, cache = step(self.params, cache, cur,
+                                     jnp.full((b,), pos, jnp.int32))
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                toks.append(nxt)
+                if pos + 1 < total:
+                    cur = jnp.where(pos + 1 < lens_d,
+                                    prompt_mat[:, pos + 1], nxt)
+            gen = self._pull(jnp.stack(toks, axis=1))  # the one read
+            self.transfers["decode_d2h"] += 1
         # request i's outputs are the argmaxes at positions
         # len(p)-1 .. len(p)-1+max_new-1 (same schedule as the per-slot
         # host loop this replaces)
@@ -441,21 +436,24 @@ class EdgeServingEngine(_ServingCore):
                 # not the generator's draw
                 active = np.zeros((self.batch_slots,), np.float32)
                 active[: len(requests)] = 1.0
-        tasks, decision, result = self._price_slot(active)
-        if requests is None:
-            act = np.flatnonzero(np.asarray(tasks.active) > 0.5)
-            slot_ids = [int(i) for i in act]
-            requests = [self.make_request() for _ in slot_ids]
-        # exact per-request latencies for the last-K ring (finished
-        # requests only; inf = unreachable link is a miss, not a time).
-        # serve_slot already syncs result.reward/decision to host each
-        # slot, so this adds no new device round-trip pattern.
-        tt = np.asarray(result.t_total, np.float64)
-        act_mask = np.asarray(tasks.active, np.float64) > 0.5
-        self._latency_ring.extend(tt[act_mask & np.isfinite(tt)].tolist())
+        with span("serve/price"):
+            tasks, decision, result = self._price_slot(active)
+            if requests is None:
+                act = np.flatnonzero(np.asarray(tasks.active) > 0.5)
+                slot_ids = [int(i) for i in act]
+                requests = [self.make_request() for _ in slot_ids]
+            # exact per-request latencies for the last-K ring (finished
+            # requests only; inf = unreachable link is a miss, not a
+            # time). ``tasks.active`` was read by the metrics update:
+            # its host copy is reused here, not read again.
+            tt = np.asarray(self._pull(result.t_total), np.float64)
+            act_mask = np.asarray(tasks.active, np.float64) > 0.5
+            self._latency_ring.extend(
+                tt[act_mask & np.isfinite(tt)].tolist())
 
-        assignments = [self._assignment(decision, slot) for slot in slot_ids]
-        self.tokens_served += sum(r.max_new for r in requests)
+            assignments = [self._assignment(decision, slot)
+                           for slot in slot_ids]
+            self.tokens_served += sum(r.max_new for r in requests)
         texts = None
         if decode:
             by_exit = {}
@@ -709,8 +707,9 @@ class ContinuousServingEngine(_ServingCore):
         active = np.zeros((self.batch_slots,), np.float32)
         for slot, _ in events.admitted:
             active[slot] = 1.0
-        _, decision, result = self._price_slot(active)
-        t_total = np.asarray(result.t_total, np.float64)
+        with span("serve/price"):
+            _, decision, result = self._price_slot(active)
+            t_total = np.asarray(self._pull(result.t_total), np.float64)
 
         # fill the admitted slots: assignment, realized latency, hold
         slots = list(self.batch.slots)

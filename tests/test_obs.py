@@ -368,6 +368,53 @@ class TestEngineTelemetry:
         assert [len(o) for o in outs] == [3, 2]
         assert all(isinstance(t, int) for o in outs for t in o)
 
+    def test_serve_slot_spans_and_pinned_host_reads(self, engine,
+                                                    tmp_path):
+        """One decoding call under a profiler capture: one ``serve/price``,
+        one ``serve/decode`` per exit group, and a ``serve/pull`` per
+        blocking read, equal in number to the ``host_pulls`` delta: six
+        in the scheduling phase (success, accuracy, active, reward,
+        decision, t_total), then one inside each exit group's decode."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        from repro.obs import trace_capture
+        from repro.serve.engine import Request
+
+        reqs = [Request(tokens=np.asarray([3, 5, 7], np.int32),
+                        deadline_s=0.05, max_new=2),
+                Request(tokens=np.asarray([2, 9], np.int32),
+                        deadline_s=0.05, max_new=3),
+                Request(tokens=np.asarray([4], np.int32),
+                        deadline_s=0.05, max_new=2)]
+        engine.serve_slot(reqs, decode=True)      # compile outside
+        before = engine.transfers["host_pulls"]
+        with trace_capture(str(tmp_path)):
+            assignments, info = engine.serve_slot(reqs, decode=True)
+        pulls = engine.transfers["host_pulls"] - before
+        groups = len({e for _, e in assignments})
+        assert [len(t) for t in info["texts"]] == [2, 3, 2]
+
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        spans = sorted(
+            (e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for p in ProfileData.from_file(path).planes
+            if not p.name.startswith("/device:")
+            for line in p.lines for e in line.events
+            if e.name.startswith("serve/"))
+        layers = [(s, t, n) for s, t, n in spans if n != "serve/pull"]
+        names = [n for _, _, n in layers]
+        assert names == ["serve/price"] + ["serve/decode"] * groups
+
+        def inside(s, t):
+            return [n for a, b, n in layers if a <= s and t <= b]
+
+        reads = [inside(s, t) for s, t, n in spans if n == "serve/pull"]
+        assert reads == [["serve/price"]] * 6 + [["serve/decode"]] * groups
+        assert pulls == len(reads) == 6 + groups
+
     def test_zero_request_snapshot_is_strict_json(self):
         # a freshly constructed engine has served nothing: every quantile
         # must be None (not NaN) and every rate 0 — no div-by-zero
