@@ -198,7 +198,8 @@ class _ServingCore:
         # long), so throughput reads as tokens/s next to requests/s
         self.tokens_served = 0
         self.transfers = {"decode_h2d": 0, "decode_d2h": 0,
-                          "telemetry_pulls": 0, "host_pulls": 0}
+                          "decode_launches": 0, "telemetry_pulls": 0,
+                          "host_pulls": 0}
         self._tel_update = jax.jit(
             lambda tel, dec, res, act, dl, rf, loss: telemetry_update(
                 tel, decisions=dec, result=res, active=act, deadline_s=dl,
@@ -349,10 +350,49 @@ class _ServingCore:
 
 
 # ============================================================== sync engine
+def make_decode_loop(cfg: ArchConfig, step, cache_len: int):
+    """The greedy, teacher-forced decode of one exit group as one program.
+
+    ``serve_steps(params, packed) -> tokens [b, cache_len]`` reads one
+    int32 array ``packed [b, cache_len + 2]``: each row's prompt padded to
+    ``cache_len``, then the prompt's length, then the group's position
+    count ``total`` (the same in every row). It makes the group's cache
+    and runs ``step`` at positions 0 .. total-1, the cache and the
+    current token carried in place; the input at each next position is
+    the prompt's next token while inside the prompt, else the argmax just
+    produced. Column ``pos`` of the result is the argmax at ``pos``
+    (columns from ``total`` on stay 0). The trip count is data, so one
+    compiled program serves every group length at a given group size.
+    """
+    model = model_for(cfg)
+
+    def serve_steps(params, packed):
+        b = packed.shape[0]
+        prompts = packed[:, :cache_len]
+        lens = packed[:, cache_len]
+        total = packed[0, cache_len + 1]
+
+        def body(pos, carry):
+            cache, cur, out = carry
+            logits, cache = step(params, cache, cur,
+                                 jnp.full((b,), pos, jnp.int32))
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            out = out.at[:, pos].set(nxt)
+            col = jax.lax.dynamic_index_in_dim(prompts, pos + 1, axis=1,
+                                               keepdims=False)
+            return cache, jnp.where(pos + 1 < lens, col, nxt), out
+
+        carry = (model.init_cache(cfg, b, cache_len), prompts[:, 0],
+                 jnp.zeros((b, cache_len), jnp.int32))
+        return jax.lax.fori_loop(0, total, body, carry)[2]
+
+    return serve_steps
+
+
 class EdgeServingEngine(_ServingCore):
     """The synchronous slot loop: one ``serve_slot`` call per MEC slot.
 
-    Per-exit compiled LM decode steps live here (the exit choice is a
+    Per-exit compiled LM decode loops live here (the exit choice is a
     compile-time schedule truncation); the scheduling decision itself
     already runs the batched actor program shared with the rollout and
     sweep layers.
@@ -361,54 +401,50 @@ class EdgeServingEngine(_ServingCore):
     def __init__(self, cfg: ArchConfig, replicas: list[Replica], **kw):
         kw.setdefault("init_model", True)
         super().__init__(cfg, replicas, **kw)
-        # one compiled decode step per (replica, exit) — exit is static
-        self._steps = {
-            e: jax.jit(make_serve_step(cfg, exit_layer=e))
-            for e in cfg.exit_layers
-        } if self.model is not None else {}
+        # per exit (static): one decode step, and the whole greedy loop
+        # over it as one program (compiled per group size)
+        steps = ({e: make_serve_step(cfg, exit_layer=e)
+                  for e in cfg.exit_layers} if self.model is not None
+                 else {})
+        self._steps = {e: jax.jit(f) for e, f in steps.items()}
+        self._loops = {e: jax.jit(make_decode_loop(cfg, f, self.cache_len))
+                       for e, f in steps.items()}
 
     # ------------------------------------------------------------- decoding
     def _decode(self, requests: list[Request], exit_layer: int) -> list:
         """Greedy-decode a batch at the given exit depth, under one
         ``serve/decode`` span.
 
-        The padded prompt matrix goes up in one explicit host->device
-        upload (``decode_h2d`` counts it), every per-position input is a
-        device-side select between the next prompt column and the token
-        just generated (teacher-forcing while inside each prompt), and
-        the generated tokens come back in **one** device->host read at
-        the end (``decode_d2h``, and a ``serve/pull``). The per-position
-        Python scalars (``pos`` and its comparison with the prompt
-        lengths) still go up implicitly, as a ``DevicePut`` each step.
+        The prompts, their lengths and the position count go up in one
+        explicit host->device upload (``decode_h2d``), the exit's decode
+        loop (``make_decode_loop``) runs every position as one launched
+        program (``decode_launches``), and the generated tokens come back
+        in one device->host read (``decode_d2h``, and a ``serve/pull``).
+        Raises ``ValueError`` when the group needs more positions than
+        the engine's ``cache_len``.
         """
         with span("serve/decode"):
             b = len(requests)
-            cache = self.model.init_cache(self.cfg, b, self.cache_len)
             prompts = [np.asarray(r.tokens, np.int32) for r in requests]
             lens = np.array([len(p) for p in prompts], np.int32)
             total = int(lens.max()) + max(r.max_new for r in requests)
-            mat = np.zeros((b, total), np.int32)
+            if total > self.cache_len:
+                raise ValueError(
+                    f"decode group needs {total} positions > cache_len "
+                    f"{self.cache_len}")
+            packed = np.zeros((b, self.cache_len + 2), np.int32)
             for i, p in enumerate(prompts):
-                mat[i, : len(p)] = p
-            prompt_mat = jnp.asarray(mat)      # the explicit upload
-            lens_d = jnp.asarray(lens)
+                packed[i, : len(p)] = p
+            packed[:, self.cache_len] = lens
+            packed[:, self.cache_len + 1] = total
+            packed = jax.device_put(packed)    # the one upload
             self.transfers["decode_h2d"] += 1
-            step = self._steps[exit_layer]
-            cur = prompt_mat[:, 0]
-            toks = []
-            for pos in range(total):
-                logits, cache = step(self.params, cache, cur,
-                                     jnp.full((b,), pos, jnp.int32))
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                toks.append(nxt)
-                if pos + 1 < total:
-                    cur = jnp.where(pos + 1 < lens_d,
-                                    prompt_mat[:, pos + 1], nxt)
-            gen = self._pull(jnp.stack(toks, axis=1))  # the one read
+            gen = self._loops[exit_layer](self.params, packed)
+            self.transfers["decode_launches"] += 1
+            gen = self._pull(gen)              # the one read
             self.transfers["decode_d2h"] += 1
         # request i's outputs are the argmaxes at positions
-        # len(p)-1 .. len(p)-1+max_new-1 (same schedule as the per-slot
-        # host loop this replaces)
+        # len(p)-1 .. len(p)-1+max_new-1
         return [[int(t) for t in
                  gen[i, lens[i] - 1: lens[i] - 1 + r.max_new]]
                 for i, r in enumerate(requests)]

@@ -365,6 +365,8 @@ class TestEngineTelemetry:
         outs = engine._decode(reqs, engine.cfg.exit_layers[0])
         assert engine.transfers["decode_h2d"] == before["decode_h2d"] + 1
         assert engine.transfers["decode_d2h"] == before["decode_d2h"] + 1
+        assert (engine.transfers["decode_launches"]
+                == before["decode_launches"] + 1)
         assert [len(o) for o in outs] == [3, 2]
         assert all(isinstance(t, int) for o in outs for t in o)
 
@@ -374,7 +376,8 @@ class TestEngineTelemetry:
         one ``serve/decode`` per exit group, and a ``serve/pull`` per
         blocking read, equal in number to the ``host_pulls`` delta: six
         in the scheduling phase (success, accuracy, active, reward,
-        decision, t_total), then one inside each exit group's decode."""
+        decision, t_total), then one inside each exit group's decode,
+        which launches one decode program."""
         import glob
 
         from jax.profiler import ProfileData
@@ -389,11 +392,13 @@ class TestEngineTelemetry:
                 Request(tokens=np.asarray([4], np.int32),
                         deadline_s=0.05, max_new=2)]
         engine.serve_slot(reqs, decode=True)      # compile outside
-        before = engine.transfers["host_pulls"]
+        before = dict(engine.transfers)
         with trace_capture(str(tmp_path)):
             assignments, info = engine.serve_slot(reqs, decode=True)
-        pulls = engine.transfers["host_pulls"] - before
+        pulls = engine.transfers["host_pulls"] - before["host_pulls"]
         groups = len({e for _, e in assignments})
+        assert (engine.transfers["decode_launches"]
+                - before["decode_launches"]) == groups
         assert [len(t) for t in info["texts"]] == [2, 3, 2]
 
         path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),

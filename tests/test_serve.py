@@ -422,3 +422,93 @@ class TestTokenAccounting:
         assert eng.tokens_served == sum(r.max_new for r in trace[:served])
         snap = eng.telemetry_snapshot()
         assert snap["summary"]["tokens_served"] == eng.tokens_served
+
+
+# ------------------------------------------------------------- decode loop
+_DECODE_ENGINES: dict = {}
+
+
+def _decode_engine(arch_id):
+    """A decoding engine on a reduced architecture, one per test file."""
+    from repro.configs import get_arch
+    if arch_id not in _DECODE_ENGINES:
+        _DECODE_ENGINES[arch_id] = EdgeServingEngine(
+            get_arch(arch_id, reduced=True), _replicas(), batch_slots=3,
+            cache_len=32, agent_kw=AGENT_KW)
+    return _DECODE_ENGINES[arch_id]
+
+
+def _prompts(engine, shapes, seed=0):
+    """Requests of the given (prompt length, max_new) with seeded tokens."""
+    from repro.serve import Request
+    rng = np.random.default_rng(seed)
+    return [Request(tokens=rng.integers(0, engine.cfg.vocab, n)
+                    .astype(np.int32), deadline_s=1.0, max_new=m)
+            for n, m in shapes]
+
+
+def _per_position(engine, requests, exit_layer):
+    """Greedy, teacher-forced decode one host-dispatched step per
+    position: the algorithm the engine's device loop must reproduce."""
+    import jax.numpy as jnp
+
+    from repro.train.steps import make_serve_step
+    step = jax.jit(make_serve_step(engine.cfg, exit_layer=exit_layer))
+    b = len(requests)
+    lens = np.array([len(r.tokens) for r in requests])
+    total = int(lens.max()) + max(r.max_new for r in requests)
+    mat = np.zeros((b, total), np.int32)
+    for i, r in enumerate(requests):
+        mat[i, : len(r.tokens)] = r.tokens
+    cache = engine.model.init_cache(engine.cfg, b, engine.cache_len)
+    cur, gen = mat[:, 0], []
+    for pos in range(total):
+        logits, cache = step(engine.params, cache, jnp.asarray(cur),
+                             jnp.full((b,), pos, jnp.int32))
+        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+        gen.append(nxt)
+        if pos + 1 < total:
+            cur = np.where(pos + 1 < lens, mat[:, pos + 1], nxt)
+    gen = np.stack(gen, axis=1)
+    return [[int(t) for t in gen[i, lens[i] - 1: lens[i] - 1 + r.max_new]]
+            for i, r in enumerate(requests)]
+
+
+class TestDecodeLoop:
+    GROUPS = {1: [(4, 5)], 3: [(3, 4), (6, 2), (1, 6)]}
+
+    @pytest.mark.parametrize("arch_id,exit_at,size", [
+        ("qwen1_5_0_5b", 0, 1), ("qwen1_5_0_5b", 0, 3),
+        ("qwen1_5_0_5b", -1, 1), ("qwen1_5_0_5b", -1, 3),
+        ("deepseek_v2_236b", 0, 3),        # MLA latent cache
+        ("rwkv6_7b", -1, 3),               # recurrent state cache
+    ])
+    def test_decode_matches_per_position_loop(self, arch_id, exit_at, size):
+        engine = _decode_engine(arch_id)
+        exit_layer = engine.cfg.exit_layers[exit_at]
+        reqs = _prompts(engine, self.GROUPS[size], seed=size)
+        want = _per_position(engine, reqs, exit_layer)
+        assert engine._decode(reqs, exit_layer) == want
+        assert [len(t) for t in want] == [r.max_new for r in reqs]
+
+    def test_one_program_for_every_group_length(self):
+        engine = _decode_engine("qwen1_5_0_5b")
+        exit_layer = engine.cfg.exit_layers[0]
+        loop = engine._loops[exit_layer]
+        launches = engine.transfers["decode_launches"]
+        sizes = []
+        for shapes in ([(2, 1), (3, 1)], [(5, 7), (1, 3)],
+                       [(9, 12), (4, 20)]):       # totals 4, 12, 29
+            engine._decode(_prompts(engine, shapes), exit_layer)
+            sizes.append(loop._cache_size())
+        assert sizes[0] == sizes[1] == sizes[2]
+        assert engine.transfers["decode_launches"] == launches + 3
+
+    def test_group_longer_than_the_cache_raises(self):
+        engine = _decode_engine("qwen1_5_0_5b")
+        reqs = _prompts(engine, [(20, engine.cache_len - 20 + 1)])
+        with pytest.raises(ValueError, match="cache_len"):
+            engine._decode(reqs, engine.cfg.exit_layers[0])
+        fits = _prompts(engine, [(20, engine.cache_len - 20)])
+        assert len(engine._decode(fits, engine.cfg.exit_layers[0])[0]) \
+            == engine.cache_len - 20
