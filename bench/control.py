@@ -1,13 +1,16 @@
-"""The correctness check's two readings for a serving cell, on the chip.
+"""The correctness check's two readings for a cell, on the chip.
 
     python3 -m bench.control --workload <cell> --seeds 1,2,3 --seconds 5
 
 For each seed it runs the cell as ``bench.run`` does, with a short
 window at the cell's own load, and prints one JSON line: the program's
-``logit_gap`` (what a run compares with its limit) and the control's,
-the plain reference with fp8 weights read at the same positions of the
-same requests. The program's readings over a dozen seeds or more set
-the lower end of the limit, the control's the upper end.
+readings (what a run compares with its limits) and the control's. For a
+serving cell that is ``logit_gap``, the control the plain reference with
+fp8 weights read at the same positions of the same requests. A driver
+with a ``control_readings`` of its own (training) reads every number of
+its check on the control run in the program's place. The program's
+readings over a dozen seeds or more set the lower end of each limit, the
+control's the upper end.
 """
 from __future__ import annotations
 
@@ -40,19 +43,27 @@ def main(argv=None, *, require_tpu: bool = True, resolved=None) -> list:
         import jax
     devs = run.devices(jax, cell["cell"]["chips"], require_tpu)
     out = []
+    driver = cell["driver"]
     for seed in [int(s) for s in args.seeds.split(",")]:
+        ctx = dict(config=cell["config"], traffic=cell["traffic"],
+                   reference=cell["reference"], seed=seed,
+                   seconds=args.seconds, trace=False, trace_dir=None,
+                   t_process=time.time(), peaks=None)
         with jax.default_device(devs[0]):
-            res = cell["driver"].run(dict(
-                config=cell["config"], traffic=cell["traffic"],
-                reference=cell["reference"], seed=seed,
-                seconds=args.seconds, trace=False, trace_dir=None,
-                t_process=time.time(), peaks=None))
-            low = control_gap(cell["reference"], cell["config"],
-                              res["checked"])
-        row = {"workload": args.workload, "seed": seed,
-               "program": res["checks"]["logit_gap"][0], "control": low,
-               "limit": res["checks"]["logit_gap"][1],
-               "checked_tokens": res["checked_tokens"],
+            res = driver.run(ctx)
+            if hasattr(driver, "control_readings"):
+                row = {"program": {k: v for k, (v, _) in
+                                   res["checks"].items()},
+                       "control": driver.control_readings(ctx),
+                       "limits": {k: lim for k, (_, lim) in
+                                  res["checks"].items()}}
+            else:
+                row = {"program": res["checks"]["logit_gap"][0],
+                       "control": control_gap(cell["reference"],
+                                              cell["config"], res["checked"]),
+                       "limit": res["checks"]["logit_gap"][1],
+                       "checked_tokens": res["checked_tokens"]}
+        row = {"workload": args.workload, "seed": seed, **row,
                "attempted": res["attempted"]}
         print(json.dumps(row), flush=True)
         out.append(row)

@@ -1,5 +1,7 @@
-"""A serving cell shrunk to a size the CPU runs in seconds."""
+"""Benchmark cells shrunk to a size the CPU runs in seconds."""
 import argparse
+import copy
+import functools
 import time
 
 TINY_MODEL = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
@@ -25,9 +27,51 @@ def tiny_cell(name: str, *, replay: bool = False):
     return cell
 
 
-def run_tiny(cell, seed=2**31 + 11, seconds=0.5, trace=0):
+SEED = 2**31 + 11
+
+
+def run_tiny(cell, seed=SEED, seconds=0.5, trace=0):
     from bench import run
     args = argparse.Namespace(workload=cell["cell"]["name"], seed=seed,
                               seconds=seconds, trace=trace)
     return run.run_cell(args, t_process=time.time(), require_tpu=False,
                         resolved=cell)
+
+
+TINY_TRAIN = dict(n_devices=4)
+TINY_AGENT = dict(hidden=[16, 8], candidates=37, n_random=4, replay=16,
+                  minibatch=8, train_every=5)
+
+
+@functools.lru_cache(maxsize=None)
+def train_cell():
+    """``grle_paper.train64`` at full size, resolved by ``manifest.resolve``
+    from ``BENCHMARK.json`` with the cell's entries from
+    ``bench/pending/``, where they wait until chip readings set its limits
+    and bound. Shared within a process: copy before changing."""
+    from bench import manifest
+    m = manifest.load_manifest()
+    pending = manifest.load_json(manifest.BENCH_DIR / "pending"
+                                 / "grle_paper.train64.json")
+    for key, entries in pending.items():
+        m[key] = m[key] + entries
+    return manifest.resolve("grle_paper.train64", m)
+
+
+def tiny_train_cell():
+    """``grle_paper.train64`` shrunk: 4 devices, hidden (16, 8), replay
+    16, minibatch 8, a train step every 5 slots, 2 fleets, 15-slot
+    episodes (three steps each). The pieces resolve once per process, so
+    every tiny run shares the reference's compiled slot."""
+    cell = dict(train_cell())
+    cell["config"] = copy.deepcopy(cell["config"])
+    cell["config"]["mec"].update(TINY_TRAIN)
+    cell["config"]["agent"].update(TINY_AGENT)
+    cell["traffic"] = dict(cell["traffic"], fleets=2, episode_slots=15)
+    return cell
+
+
+def train_ctx(cell, seed=SEED):
+    """The driver's context for ``cell`` without a run: for the control."""
+    return dict(config=cell["config"], traffic=cell["traffic"],
+                reference=cell["reference"], seed=seed)

@@ -19,3 +19,27 @@ def test_control_fails_the_limit_and_the_program_passes():
     for row in rows:
         assert row["checked_tokens"] > 0
         assert row["program"] <= row["limit"] < row["control"], row
+
+
+def test_training_control_row_holds_every_number():
+    """A driver with ``control_readings`` of its own (training) gets one
+    row per seed: every number of its check read on the program and on
+    the control, beside its limit."""
+    import types
+    from bench_tiny import tiny_train_cell
+    cell = tiny_train_cell()
+    seen = []
+    cell["driver"] = types.SimpleNamespace(
+        run=lambda ctx: seen.append(ctx["seed"]) or {
+            "checks": {"loss_gap": [1e-7, 0.01], "bad_episodes": [0, 0]},
+            "attempted": 30},
+        control_readings=lambda ctx: {"loss_gap": 0.5})
+    rows = control.main(["--workload", "grle_paper.train64", "--seeds",
+                         "3,4", "--seconds", "0.1"],
+                        require_tpu=False, resolved=cell)
+    assert seen == [3, 4]
+    assert rows[0] == {"workload": "grle_paper.train64", "seed": 3,
+                       "program": {"loss_gap": 1e-7, "bad_episodes": 0},
+                       "control": {"loss_gap": 0.5},
+                       "limits": {"loss_gap": 0.01, "bad_episodes": 0},
+                       "attempted": 30}

@@ -7,7 +7,10 @@ import pytest
 
 from bench import manifest, traffic
 
-MIXES = sorted(p.stem for p in (manifest.BENCH_DIR / "traffic").glob("*.json"))
+# the request mixes; a training job's mix (fleets, episode length) sends
+# no requests
+MIXES = sorted(p.stem for p in (manifest.BENCH_DIR / "traffic").glob("*.json")
+               if "block" in manifest.load_json(p))
 
 
 def take(mix, seed, n, vocab=1000):
