@@ -3,8 +3,9 @@
 The system under test is ``EdgeServingEngine.serve_slot(requests,
 decode=True)``: one call prices a slot of up to ``batch_slots`` requests
 with the GRLE scheduler and greedy-decodes each exit group through the
-KV cache. The benchmark makes the weights from the seed, hands them to
-the engine, and drives it with the cell's traffic in a closed loop:
+KV cache. The benchmark makes the weights from the seed, puts them in
+the engine's layout (``bench/layouts/<layout>.py``, named by the
+configuration), hands them to the engine, and drives it with the cell's traffic in a closed loop:
 ``clients`` requests per call, the next call as soon as the previous one
 returned; latency runs from the call to its return.
 
@@ -29,54 +30,12 @@ CHECK_ROWS = 8          # reference rows per compiled call
 TRACE_SECONDS = 3.0     # traced part of the window in a --trace 1 run
 
 
-def arch_config(cfg: dict):
-    from repro.models.config import ArchConfig
-    m = cfg["model"]
-    return ArchConfig(
-        arch_id=cfg["name"], family="dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        d_ff=m["intermediate_size"], vocab=m["vocab_size"],
-        attn_kind="gqa", n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], qkv_bias=True,
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        exit_layers=tuple(cfg["exit_layers"]),
-        dtype={"bfloat16": "bfloat16", "float32": "float32"}[m["torch_dtype"]])
-
-
-def program_params(w: dict, arch):
-    """The benchmark's weights in the engine's parameter layout.
-
-    Checked leaf by leaf against the layout the model's own ``init``
-    would build, so a change of layout fails here and not in silence.
-    """
-    import jax
-    import jax.numpy as jnp
-    from repro.models.lm import model_for
-    p = {
-        "embed": {"table": w["embed"]},
-        "blocks": {
-            "ln1": {"scale": w["ln1"]},
-            "attn": {"wq": {"w": w["wq"], "b": w["bq"]},
-                     "wk": {"w": w["wk"], "b": w["bk"]},
-                     "wv": {"w": w["wv"], "b": w["bv"]},
-                     "wo": {"w": w["wo"]}},
-            "ln2": {"scale": w["ln2"]},
-            "ffn": {"w1": {"w": w["w_gate"]}, "w3": {"w": w["w_up"]},
-                    "w2": {"w": w["w_down"]}},
-        },
-        "final_norm": {"scale": w["final_norm"]},
-        "lm_head": {"w": jax.jit(jnp.transpose)(w["embed"])},   # tied head
-        "exit_norms": {"scale": jnp.concatenate(
-            [w["exit_norm"], w["final_norm"][None]], 0)},
-    }
-    want = jax.eval_shape(lambda k: model_for(arch).init(k, arch),
-                          jax.random.PRNGKey(0))
-    got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), p)
-    exp = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), want)
-    if got != exp:
-        raise ValueError(f"engine parameter layout changed:\n{exp}\n"
-                         f"!=\n{got}")
-    return p
+def layout(cfg: dict):
+    """The configuration's weight layout, ``bench/layouts/<layout>.py``:
+    its ``arch_config(cfg)`` and ``program_params(weights, arch)``."""
+    from bench import manifest
+    return manifest.load_module(manifest.BENCH_DIR / "layouts"
+                                / f"{cfg['layout']}.py")
 
 
 class Compiles:
@@ -181,10 +140,11 @@ def run(ctx: dict) -> dict:
     compiles = Compiles()
     compiles.on = True
 
-    arch = arch_config(cfg)
+    lay = layout(cfg)
+    arch = lay.arch_config(cfg)
     vocab = arch.vocab
     weights = ref.make_weights(cfg, seed)
-    params = program_params(weights, arch)
+    params = lay.program_params(weights, arch)
     jax.block_until_ready(params)
     bs, width = cfg["batch_slots"], int(mix["clients"])
     if width > bs:

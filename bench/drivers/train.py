@@ -22,7 +22,9 @@ its first step is lr * sign(g), a step function of the gradient.
 """
 from __future__ import annotations
 
+import functools
 import gc
+import json
 import os
 import shutil
 import time
@@ -60,6 +62,19 @@ def agent(cfg: dict, env):
                      n_candidates=a["candidates"], n_random=a["n_random"],
                      buffer_size=a["replay"], batch_size=a["minibatch"],
                      train_every=a["train_every"], lr=a["lr"])
+
+
+@functools.lru_cache(maxsize=1)
+def rollout(cfg_json: str, n_fleets: int):
+    """The system under test for a configuration (as JSON): its
+    ``AgentDef`` and its ``RolloutDriver`` over ``n_fleets`` fleets. Kept
+    for the process, so that a second run in it (``bench.control``'s
+    seeds, the tests) reuses the compiled episode."""
+    from repro.mec import MECEnv
+    from repro.rollout import RolloutDriver
+    cfg = json.loads(cfg_json)
+    adef = agent(cfg, MECEnv(mec_config(cfg)))
+    return adef, RolloutDriver(adef, n_fleets=n_fleets)
 
 
 def flat(tree, prefix: str = "") -> dict:
@@ -170,8 +185,6 @@ def host(tree):
 
 def run(ctx: dict) -> dict:
     import jax
-    from repro.mec import MECEnv
-    from repro.rollout import RolloutDriver
     from bench.drivers.serve import Compiles, log
 
     cfg, mix, ref = ctx["config"], ctx["traffic"], ctx["reference"]
@@ -180,8 +193,7 @@ def run(ctx: dict) -> dict:
     compiles.on = True
 
     n_fleets, n_slots = int(mix["fleets"]), int(mix["episode_slots"])
-    adef = agent(cfg, MECEnv(mec_config(cfg)))
-    drv = RolloutDriver(adef, n_fleets=n_fleets)
+    adef, drv = rollout(json.dumps(cfg, sort_keys=True), n_fleets)
     k_agent, k_eps = ref.keys(seed)
 
     def key(i):

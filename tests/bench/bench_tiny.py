@@ -1,5 +1,6 @@
 """Benchmark cells shrunk to a size the CPU runs in seconds."""
 import argparse
+import contextlib
 import copy
 import functools
 import time
@@ -75,3 +76,17 @@ def train_ctx(cell, seed=SEED):
     """The driver's context for ``cell`` without a run: for the control."""
     return dict(config=cell["config"], traffic=cell["traffic"],
                 reference=cell["reference"], seed=seed)
+
+
+@contextlib.contextmanager
+def quick_compiles():
+    """XLA's optimisation passes off while the tiny training runs compile
+    their episode and the reference's slot: the tests check what the
+    programs compute, and compile in half the time."""
+    import jax
+    old = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", True)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_disable_most_optimizations", old)

@@ -35,7 +35,7 @@ def test_scheduling_engine_makes_the_window_decisions():
     from repro.serve import EdgeServingEngine, Replica, Request
     cell = tiny_cell(CELL)
     cfg = cell["config"]
-    arch = cell["driver"].arch_config(cfg)
+    arch = cell["driver"].layout(cfg).arch_config(cfg)
 
     def engine(model):
         return EdgeServingEngine(

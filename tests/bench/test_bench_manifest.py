@@ -82,6 +82,8 @@ def test_cell_resolves_every_piece_by_name(cell):
     for name, reader in r["readers"].items():
         assert callable(reader.read), name
     assert 1 <= r["traffic"]["clients"] <= r["config"]["batch_slots"]
+    lay = r["driver"].layout(r["config"])   # found by the config's name
+    assert callable(lay.arch_config) and callable(lay.program_params)
 
 
 @pytest.mark.parametrize("cell", CELLS)

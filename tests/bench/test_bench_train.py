@@ -5,14 +5,16 @@ weights' change, and the control (the reference with float8 actor
 matmul operands) fails the check."""
 import pytest
 
-from bench_tiny import SEED, run_tiny, tiny_train_cell, train_ctx
+from bench_tiny import (SEED, quick_compiles, run_tiny, tiny_train_cell,
+                        train_ctx)
 
 
 @pytest.fixture(scope="module")
 def sound():
-    cell = tiny_train_cell()
-    line, out = run_tiny(cell, seed=SEED, seconds=0.3)
-    return cell, line, out
+    with quick_compiles():
+        cell = tiny_train_cell()
+        line, out = run_tiny(cell, seed=SEED, seconds=0.3)
+        yield cell, line, out
 
 
 def test_sound_run_is_correct(sound):
@@ -38,8 +40,12 @@ def test_program_agrees_with_the_reference(sound):
 
 
 def test_control_fails_the_check(sound):
+    import jax
     cell, line, _ = sound
-    got = cell["driver"].control_readings(train_ctx(cell, SEED))
+    # on the run's device, as bench.control reads it: the reference's
+    # compiled slot is then the one the sound run's check compiled
+    with jax.default_device(jax.devices()[0]):
+        got = cell["driver"].control_readings(train_ctx(cell, SEED))
     limits = cell["config"]["limits"]
     assert set(got) == set(limits)
     assert any(got[k] > limits[k] for k in limits), got
